@@ -21,35 +21,13 @@ the first replicated, divisible dimension of each leaf spec.
 """
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
-
-# version-portable shard_map: the experimental module is the home through
-# jax 0.4.x; later releases promote it to jax.shard_map
-try:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-except ImportError:  # pragma: no cover - newer jax
-    shard_map = jax.shard_map  # noqa: F401
-
-
-def make_abstract_mesh(shape: Sequence[int],
-                       axis_names: Sequence[str]) -> AbstractMesh:
-    """Version-portable AbstractMesh constructor.
-
-    jax <= 0.4.37 takes one `((name, size), ...)` shape tuple; later
-    releases take `(sizes, names)` positionally. Rules code should build
-    meshes through this shim instead of calling AbstractMesh directly."""
-    if len(shape) != len(axis_names):
-        raise ValueError(f"shape {shape} vs axis_names {axis_names}")
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
 
 # leaves that stay replicated regardless of shape (small / awkward to split)
 _REPLICATED_NAMES = {

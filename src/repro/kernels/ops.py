@@ -63,7 +63,8 @@ def _decode_block_default(group: int, head_dim: int) -> int:
         lo=128, hi=4096)
 
 
-def _resolve_impl(impl: str) -> str:
+def resolve_impl(impl: str) -> str:
+    """"auto" -> "pallas" on a TPU, "jnp" elsewhere; else `impl` itself."""
     if impl == "auto":
         return "jnp" if _interpret() else "pallas"
     if impl not in ("pallas", "jnp"):
@@ -123,7 +124,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, k_new, v_new,
     b, _, h, d = q.shape
     kvh, bs = k_pages.shape[1], k_pages.shape[2]
     g = h // kvh
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if impl == "jnp":
         return _paged_decode_jnp(q, k_pages, v_pages, tables, lengths,
                                  k_new, v_new, max_len)
@@ -180,7 +181,7 @@ def paged_prefill_attention(q, k_pages, v_pages, table, ctx: int,
     _, c, h, d = q.shape
     kvh, bs = k_pages.shape[1], k_pages.shape[2]
     g = h // kvh
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     if table.shape[0] == 0:
         table = jnp.full((1,), k_pages.shape[0] - 1, jnp.int32)  # dump page
     if impl == "jnp":

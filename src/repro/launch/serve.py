@@ -6,12 +6,16 @@ Uses reduced-config models so the full pipeline (prefill -> paged KV ->
 speculative rounds -> verification -> carbon accounting) executes with
 real numerics on CPU; on TPU pools the same engine runs the full configs
 (--arch/--draft-arch select any registry entry, --full disables the
-reduction).
+reduction). `make_model` and `build_engine` are the construction steps
+`main` uses; chip_smoke.py at the repository root calls the same two.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -19,9 +23,54 @@ import numpy as np
 from repro.configs import get_config, get_reduced_config
 from repro.core.carbon import GRID_CI
 from repro.core.spec_decode import SpecConfig
-from repro.models import init_params
+from repro.models import ModelConfig, init_params
 from repro.serving.engine import ServingEngine
-from repro.serving.workload import DATASETS, sample_requests
+from repro.serving.workload import DATASETS
+
+# the checkout's own compile-cache directory (listed in .gitignore)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX already keeps the cache
+    there and nothing is changed. Otherwise the cache goes to one fixed
+    directory inside the checkout, so a later run of this checkout finds
+    what an earlier one compiled. Call it when an entry point's main
+    starts, never when a module is imported."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def make_model(arch: str, full: bool, seed: int,
+               draft: bool = False) -> "tuple[ModelConfig, dict]":
+    """(config, params) of a registry arch, weights random from `seed`.
+
+    `full` keeps the published config; otherwise the CPU-scale reduction
+    is used, and a draft model's FFN shrinks further so it stays cheaper
+    than its target."""
+    cfg = get_config(arch) if full else get_reduced_config(arch)
+    if draft and not full:
+        cfg = dataclasses.replace(cfg, name=cfg.name + "-draft", d_ff=128)
+    return cfg, init_params(jax.random.PRNGKey(seed), cfg)
+
+
+def build_engine(tcfg: ModelConfig, tparams, kind: str, *, dcfg=None,
+                 dparams=None, spec_k: int = 4, new_chip: str = "tpu_v5e",
+                 old_chip: str = "tpu_v2", temperature: float = 1.0,
+                 seed: int = 0, batching=None) -> ServingEngine:
+    """The launcher's engine: the old chip joins only the disaggregated
+    kinds (dpd/dsd), and spec/dsd draft `spec_k` tokens per round."""
+    return ServingEngine(
+        tcfg, tparams, kind=kind, draft_cfg=dcfg, draft_params=dparams,
+        spec=SpecConfig(num_draft_tokens=spec_k),
+        new_chip=new_chip,
+        old_chip=old_chip if kind in ("dpd", "dsd") else None,
+        temperature=temperature, seed=seed, batching=batching)
 
 
 def main() -> None:
@@ -43,26 +92,16 @@ def main() -> None:
                     help="use the full config (TPU-scale; not for CPU)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
-    get_cfg = get_config if args.full else get_reduced_config
-    tcfg = get_cfg(args.arch)
-    needs_draft = args.kind in ("spec", "dsd")
-    dcfg = None
-    dparams = None
-    if needs_draft:
-        dcfg = get_cfg(args.draft_arch)
-        if not args.full:
-            import dataclasses
-
-            dcfg = dataclasses.replace(dcfg, name=dcfg.name + "-draft", d_ff=128)
-        dparams = init_params(jax.random.PRNGKey(args.seed + 1), dcfg)
-    tparams = init_params(jax.random.PRNGKey(args.seed), tcfg)
-
-    engine = ServingEngine(
-        tcfg, tparams, kind=args.kind, draft_cfg=dcfg, draft_params=dparams,
-        spec=SpecConfig(num_draft_tokens=args.spec_k),
-        new_chip=args.new_chip,
-        old_chip=args.old_chip if args.kind in ("dpd", "dsd") else None,
+    tcfg, tparams = make_model(args.arch, args.full, args.seed)
+    dcfg = dparams = None
+    if args.kind in ("spec", "dsd"):
+        dcfg, dparams = make_model(args.draft_arch, args.full, args.seed + 1,
+                                   draft=True)
+    engine = build_engine(
+        tcfg, tparams, args.kind, dcfg=dcfg, dparams=dparams,
+        spec_k=args.spec_k, new_chip=args.new_chip, old_chip=args.old_chip,
         temperature=args.temperature, seed=args.seed)
 
     ds = DATASETS[args.dataset]
@@ -90,7 +129,8 @@ def main() -> None:
         print(f"interconnect traffic: {engine.link_bytes/1e6:.2f} MB")
     ttfts = [r.ttft_s for r in done]
     tpots = [r.tpot_s for r in done if len(r.out_tokens) > 1]
-    print(f"TTFT mean {np.mean(ttfts)*1e3:.1f}ms  TPOT mean {np.mean(tpots)*1e3:.2f}ms "
+    print(f"modeled TTFT mean {np.mean(ttfts)*1e3:.1f}ms  "
+          f"TPOT mean {np.mean(tpots)*1e3:.2f}ms "
           f"(SLO: {ds.ttft_slo_s*1e3:.0f}/{ds.tpot_slo_s*1e3:.0f} ms)")
     from repro.core.carbon import CHIP_DB, request_carbon
 

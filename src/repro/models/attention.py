@@ -9,7 +9,7 @@ static_unroll (cost) mode it is a Python loop with *static causal slicing*
 of K/V so HLO FLOPs reflect the causal ~S^2/2 work.
 
 The Pallas flash-attention kernel (kernels/flash_attention.py) implements
-the same contract for the TPU hot path; `exec_cfg.use_kernels` routes to it.
+the same contract for the TPU hot path; `exec_cfg.kernels` routes to it.
 """
 from __future__ import annotations
 
@@ -81,7 +81,7 @@ def multihead_attention(
     """Full-sequence causal attention, blocked over query blocks."""
     b, s, h, d = q.shape
     qb = min(exec_cfg.q_block, s)
-    if exec_cfg.use_kernels:
+    if exec_cfg.kernels:
         from repro.kernels import ops as kops
 
         return kops.flash_attention(q, k, v, causal=cfg_attn.causal)
@@ -131,7 +131,7 @@ def decode_attention(
     exec_cfg: ExecConfig = DEFAULT_EXEC,
 ) -> jax.Array:
     """Single-token attention against a (padded) KV cache."""
-    if exec_cfg.use_kernels:
+    if exec_cfg.kernels:
         from repro.kernels import ops as kops
 
         return kops.decode_attention(q, k_cache, v_cache, pos)

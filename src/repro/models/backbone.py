@@ -16,6 +16,11 @@ of depth); `exec_cfg.static_unroll` switches to Python loops for the cost
 dry-run (XLA cost analysis counts scan bodies once - see DESIGN.md §7).
 Training remat: the scan body is `jax.checkpoint`-ed, so only layer-boundary
 activations are saved.
+
+`init_params` and the step functions the serving engine calls (`prefill`,
+`serve_step`, `serve_step_paged`, `prefill_chunk_paged`) are jitted with
+the configs static, so a shape seen once in a process is not compiled
+again.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models import mamba2, rwkv6
 from repro.models.attention import (
     attention_block,
@@ -79,6 +85,7 @@ def _init_layer(rng: jax.Array, cfg: ModelConfig) -> dict:
     return p
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     k_embed, k_layers, k_shared = jax.random.split(rng, 3)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
@@ -291,6 +298,7 @@ def forward(params: Params, batch: dict, cfg: ModelConfig,
     return lm_logits(params["tok"], x, cfg)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "exec_cfg"))
 def prefill(params: Params, batch: dict, cfg: ModelConfig,
             exec_cfg: ExecConfig = DEFAULT_EXEC) -> tuple[jax.Array, Cache]:
     """Prompt processing: returns (logits at last position (B, V), cache)."""
@@ -341,6 +349,7 @@ def _attn_layer_step(lp, x, kc, vc, pos, prope, cfg, exec_cfg):
     return x, kc, vc
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "exec_cfg"))
 def serve_step(params: Params, cache: Cache, tokens: jax.Array, cfg: ModelConfig,
                exec_cfg: ExecConfig = DEFAULT_EXEC,
                embeds: Optional[jax.Array] = None) -> tuple[jax.Array, Cache]:
@@ -466,9 +475,22 @@ def serve_step_paged(params: Params, pages_k: jax.Array, pages_v: jax.Array,
     (impl="jnp") the logits are bit-identical to `serve_step` over the
     gathered cache. Dense + MoE families only (decode feeds all B tokens
     through MoE as one group either way, so MoE capacity routing is
-    unaffected; recurrent/vlm families keep the gather path)."""
+    unaffected; recurrent/vlm families keep the gather path).
+
+    `max_len` only sizes the jnp twin's densified cache; the Pallas kernel
+    reads `lengths` at run time, so on that path it is dropped and the
+    step compiles once per (batch, table width), not once per step."""
     assert cfg.family in ("dense", "moe"), cfg.family
-    b = tokens.shape[0]
+    impl = kops.resolve_impl(impl)
+    return _serve_step_paged(params, pages_k, pages_v, tables, lengths, tokens,
+                             cfg, exec_cfg, max_len if impl == "jnp" else 0,
+                             impl)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "exec_cfg", "max_len", "impl"))
+def _serve_step_paged(params, pages_k, pages_v, tables, lengths, tokens,
+                      cfg, exec_cfg, max_len, impl):
     x = embed_tokens(params["tok"], tokens)[:, None, :]            # (B, 1, D)
     prope = lengths[:, None].astype(jnp.int32)                     # (B, 1)
 
@@ -506,8 +528,23 @@ def prefill_chunk_paged(params: Params, pages_k: jax.Array, pages_v: jax.Array,
 
     Dense family only: MoE capacity routing drops tokens per *group*, so an
     MoE chunk processed alone routes differently than inside the full
-    prefix - incremental results would diverge from the recompute path."""
+    prefix - incremental results would diverge from the recompute path.
+
+    `ctx0` is static only for the jnp twin, which slices the context by
+    it; the Pallas kernel reads it at run time, so there it is traced and
+    chunks of one length and table width share a compile."""
     assert cfg.family == "dense", cfg.family
+    impl = kops.resolve_impl(impl)
+    if impl == "jnp":
+        return _prefill_chunk_static_ctx(params, pages_k, pages_v, table, ctx0,
+                                         tokens, cfg, exec_cfg, impl)
+    return _prefill_chunk_traced_ctx(params, pages_k, pages_v, table,
+                                     jnp.int32(ctx0), tokens, cfg, exec_cfg,
+                                     impl)
+
+
+def _prefill_chunk_paged(params, pages_k, pages_v, table, ctx0, tokens, cfg,
+                         exec_cfg, impl):
     c = tokens.shape[0]
     x = embed_tokens(params["tok"], tokens[None, :])               # (1, C, D)
 
@@ -525,6 +562,12 @@ def prefill_chunk_paged(params: Params, pages_k: jax.Array, pages_v: jax.Array,
     logits = lm_logits(params["tok"], xn, cfg)[:, 0]
     # kt: (L, 1, C, KV, D) -> (L, KV, C, D) for scatter_chunk
     return logits, kt[:, 0].transpose(0, 2, 1, 3), vt[:, 0].transpose(0, 2, 1, 3)
+
+
+_prefill_chunk_static_ctx = jax.jit(
+    _prefill_chunk_paged, static_argnames=("ctx0", "cfg", "exec_cfg", "impl"))
+_prefill_chunk_traced_ctx = jax.jit(
+    _prefill_chunk_paged, static_argnames=("cfg", "exec_cfg", "impl"))
 
 
 def extend_step(params: Params, cache: Cache, tokens: jax.Array, cfg: ModelConfig,

@@ -34,7 +34,9 @@ class ExecConfig:
 
     static_unroll: bool = False
     q_block: int = 1024          # attention query-block length
-    use_kernels: bool = False    # route hot ops through Pallas kernels
+    # route hot ops through the Pallas kernels; None lets the platform
+    # decide (kernels on TPU, the jnp reference elsewhere - see `kernels`)
+    use_kernels: Optional[bool] = None
     remat: bool = True           # checkpoint scan bodies during training
     moe_group_size: int = 4096   # tokens per MoE dispatch group
     # Megatron-style sequence parallelism: PartitionSpec entries (as a
@@ -49,6 +51,14 @@ class ExecConfig:
     # expert FFN einsum is fully local - without it XLA all-gathers the
     # expert weight banks every layer (EXPERIMENTS.md §Perf iteration 4).
     ep_axes: tuple | None = None
+
+    @property
+    def kernels(self) -> bool:
+        """Whether hot ops run the Pallas kernels: `use_kernels` when set,
+        else exactly when JAX's default backend is a TPU."""
+        if self.use_kernels is None:
+            return jax.default_backend() == "tpu"
+        return self.use_kernels
 
 
 DEFAULT_EXEC = ExecConfig()

@@ -184,7 +184,7 @@ def mamba2_block(
     conv_state = jnp.concatenate([cs_x, cs_b, cs_c], axis=-1)
     xh = xs.reshape(bsz, t, nheads, s.head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
-    if exec_cfg.use_kernels:
+    if exec_cfg.kernels:
         from repro.kernels import ops as kops
 
         y, state = kops.mamba2_ssd(xh, b_in, c_in, dt, p["a_log"], state0, chunk=s.chunk_size)

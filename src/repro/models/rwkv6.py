@@ -181,7 +181,7 @@ def time_mix(
     vv = (xv @ p["wv"]).reshape(b, t, h, r_cfg.head_dim)
     g = jax.nn.silu(xg @ p["wg"])
     logw = _decay(p, xw).reshape(b, t, h, r_cfg.head_dim)
-    if exec_cfg.use_kernels:
+    if exec_cfg.kernels:
         from repro.kernels import ops as kops
 
         y, state = kops.rwkv6_wkv(rr, kk, vv, logw, p["u"], state0)

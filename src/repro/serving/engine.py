@@ -163,12 +163,13 @@ class ServingEngine:
         # runs incrementally against the paged context. Spec rounds keep
         # the gather path (the extend/rollback contract needs a contiguous
         # window); recurrent/vlm families have no paged attention.
-        # paged="auto" follows exec_cfg.use_kernels; True/False force it.
+        # paged="auto" follows exec_cfg.kernels - on by default exactly on
+        # a TPU, where the Pallas kernels run; True/False force it.
         fam_ok = (target_cfg.family in ("dense", "moe")
                   and target_cfg.attn is not None
                   and target_cfg.attn.m_rope_sections is None)
         if paged == "auto":
-            self.paged = bool(exec_cfg.use_kernels) and fam_ok
+            self.paged = exec_cfg.kernels and fam_ok
         else:
             self.paged = bool(paged)
             if self.paged and not fam_ok:

@@ -114,8 +114,6 @@ def make_compressed_train_step(
     Params are replicated over `data_axis`; each shard computes grads on
     its batch slice; the sync is the int8 error-feedback all-reduce. State
     carries the per-shard residual."""
-    from jax.experimental.shard_map import shard_map
-
     def step(params, opt_state, residual, batch):
         def shard_fn(params, opt_state, residual, batch):
             residual = jax.tree.map(lambda r: r[0], residual)  # drop shard dim
@@ -130,7 +128,7 @@ def make_compressed_train_step(
         rep = P()
         bspec = jax.tree.map(lambda _: P(data_axis), batch)
         rspec = jax.tree.map(lambda _: P(data_axis), residual)  # per-shard state
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, params),
                       jax.tree.map(lambda _: rep, opt_state),
@@ -139,7 +137,7 @@ def make_compressed_train_step(
                        jax.tree.map(lambda _: rep, opt_state),
                        rspec,
                        {"loss": rep, "grad_norm": rep, "lr": rep}),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, residual, batch)
 
     return jax.jit(step)

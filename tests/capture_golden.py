@@ -10,7 +10,9 @@ refactored simulator must reproduce every value bit-exactly
 (tests/test_parity_golden.py); floats survive the JSON round-trip exactly
 because Python serializes doubles with repr precision.
 """
+import functools
 import json
+import operator
 import os
 
 from repro.configs import get_config
@@ -63,7 +65,10 @@ def run_case(mode: ServingMode):
                 "n_segments": len(u.segments),
                 "seg_first": list(u.segments[0]) if u.segments else None,
                 "seg_last": list(u.segments[-1]) if u.segments else None,
-                "seg_sum_energy": sum(s[2] for s in u.segments),
+                # left-to-right fold: Python >= 3.12's float sum() is
+                # compensated, which is not the sum the golden froze
+                "seg_sum_energy": functools.reduce(
+                    operator.add, (s[2] for s in u.segments), 0),
             }
             for name, u in sorted(res.use.items())
         },

@@ -8,20 +8,19 @@ import textwrap
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.fault import HeartbeatTracker, StragglerPolicy
 from repro.distributed.sharding import (
     cache_pspecs,
-    make_abstract_mesh,
     param_pspecs,
     tokens_pspec,
     zero_variant,
 )
 
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(arch, mesh=MESH):
@@ -198,13 +197,12 @@ def test_elastic_failover_8dev(tmp_path):
 def test_int8_allreduce_accuracy_8dev():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AbstractMesh, PartitionSpec as P
         from repro.launch.mesh import make_host_mesh
         from repro.distributed.compression import int8_allreduce_mean
-        from repro.distributed.sharding import shard_map
         mesh = make_host_mesh(data=8, model=1)
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda s: int8_allreduce_mean(s[0], "data")[None],
             mesh=mesh, in_specs=P("data"), out_specs=P("data")))
         got = np.asarray(f(x))[0]
