@@ -1,0 +1,7 @@
+"""Fused paged prefill attention kernel, batch cells: roofline least time
+over its device time (%)."""
+from chipbench.lib.layer_metrics import prefill_kernel_share
+
+
+def reduce(ctx):
+    return prefill_kernel_share(ctx)

@@ -1,0 +1,7 @@
+"""Whole prefill chunk step (prefill_chunk_paged), online cells: roofline
+least time over its device time (%)."""
+from chipbench.lib.layer_metrics import chunk_step_share
+
+
+def reduce(ctx):
+    return chunk_step_share(ctx)
