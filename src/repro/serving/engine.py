@@ -33,6 +33,7 @@ per-iteration timing model at scales the CPU cannot execute.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Optional
 
@@ -88,6 +89,11 @@ class EngineRequest:
     deadline_s: Optional[float] = None
     cancel_at_s: Optional[float] = None
     status: str = "ok"
+    # wall clock (time.perf_counter) at submit(), and the wall-clock wait
+    # until a plan first held a chunk of it (None until then); measurement
+    # only, never read by scheduling or pricing
+    submit_wall_s: float = float("nan")
+    queue_wait_s: Optional[float] = None
 
     @property
     def done(self) -> bool:
@@ -97,6 +103,16 @@ class EngineRequest:
     def tpot_s(self) -> float:
         n = len(self.out_tokens)
         return 0.0 if n <= 1 else (self.last_token_s - self.first_token_s) / (n - 1)
+
+
+@dataclasses.dataclass
+class EngineCounters:
+    """Running totals of the continuous single-pool path, for measurement
+    only; a reader takes the difference of two `dataclasses.asdict`
+    snapshots."""
+    queued: int = 0              # requests first scheduled (a chunk in a plan)
+    queue_wait_s: float = 0.0    # the sum of their EngineRequest.queue_wait_s
+    preempted: int = 0           # len(plan.preempted), summed over plans
 
 
 class ServingEngine:
@@ -200,6 +216,7 @@ class ServingEngine:
         self.rounds = 0
         self.accepted = 0
         self.proposed = 0
+        self.counters = EngineCounters()
         # continuous-policy state: the SAME scheduler construction as the
         # simulator's (batching.py factories), so both executors replay
         # identical schedules on identical workloads
@@ -262,7 +279,8 @@ class ServingEngine:
             raise ValueError(f"cancel_at_s {cancel_at_s} precedes arrival_s")
         r = EngineRequest(self._next_id, np.asarray(prompt, np.int32),
                           max_new_tokens, arrival_s, slo_class=slo_class,
-                          deadline_s=deadline_s, cancel_at_s=cancel_at_s)
+                          deadline_s=deadline_s, cancel_at_s=cancel_at_s,
+                          submit_wall_s=time.perf_counter())
         if deadline_s is not None or cancel_at_s is not None:
             self._lifecycle = True
         self._next_id += 1
@@ -391,14 +409,21 @@ class ServingEngine:
         Fault semantics mirror `ReplicaSim.advance_to`: every iteration
         that *begins* before the scripted kill time runs to completion
         and stays charged (non-preemptive), then `kill()` fires and
-        step() returns False for good."""
+        step() returns False for good.
+
+        Host spans (`jax.profiler.TraceAnnotation`, on the profiler's
+        clock when it records): `engine.step` around every step; inside
+        the continuous path, `engine.schedule`, `engine.prefill`,
+        `engine.decode`, `engine.sample` and `engine.emit`, so the
+        innermost `engine.*` span names the phase at any moment."""
         if self.dead:
             return False
-        if self.policy.kind == "continuous":
-            if self.kind == "dpd":
-                return self._step_continuous_dpd()
-            return self._step_continuous()
-        return self._step_serialized()
+        with jax.profiler.TraceAnnotation("engine.step"):
+            if self.policy.kind == "continuous":
+                if self.kind == "dpd":
+                    return self._step_continuous_dpd()
+                return self._step_continuous()
+            return self._step_serialized()
 
     def _step_serialized(self) -> bool:
         while True:
@@ -743,65 +768,71 @@ class ServingEngine:
         identical workload both executors replay the identical schedule
         (tests/test_engine_sim_parity.py, continuous rows)."""
         sched = self._sched
-        while True:
-            if self.clock >= self._kill_s:
-                self.kill(self._kill_s)
-                return False
-            self._admit_continuous(sched)
-            if self._lifecycle:
-                self._expire_sched(sched, self.clock)
-            if sched.cache is not None:
-                sched.cache.now_s = self.clock    # carbon lookup only
-            plan = sched.next_plan()
-            if plan is not None:
-                break
-            if not self.waiting:
-                if self._kill_s < float("inf"):
+        with jax.profiler.TraceAnnotation("engine.schedule"):
+            while True:
+                if self.clock >= self._kill_s:
                     self.kill(self._kill_s)
-                return False
-            self.clock = max(self.clock, self.waiting[0].arrival_s)
-        for victim in plan.preempted:
-            # scheduler already freed its ledger (and released its cache
-            # refs) and reset the seq for recompute; mirror on the real
-            # pools (tokens are kept - the re-prefill recomputes prompt +
-            # emitted prefix)
-            self._shared_tok.pop(victim.sid, None)
-            self.pool.free(victim.sid)
-            if self.draft_pool is not None:
-                self.draft_pool.free(victim.sid)
-        k = self.spec.num_draft_tokens
-        hs = hybrid_step_charges(
-            self.kind, self.cfg, self.draft_cfg, self.new_chip, self.old_chip,
-            plan.chunk_specs(), plan.decode_ctxs(), k, self.interconnect)
-        for chip_name, cost, rel_s in hs.charges:
-            self._charge(CHIP_DB[chip_name], cost, at_s=self.clock + rel_s)
-        t_end = self.clock + self._dilate(self.clock, hs.duration_s)
-        if sched.cache is not None:
-            sched.cache.now_s = t_end             # publish at step-end time
+                    return False
+                self._admit_continuous(sched)
+                if self._lifecycle:
+                    self._expire_sched(sched, self.clock)
+                if sched.cache is not None:
+                    sched.cache.now_s = self.clock    # carbon lookup only
+                plan = sched.next_plan()
+                if plan is not None:
+                    break
+                if not self.waiting:
+                    if self._kill_s < float("inf"):
+                        self.kill(self._kill_s)
+                    return False
+                self.clock = max(self.clock, self.waiting[0].arrival_s)
+            for victim in plan.preempted:
+                # scheduler already freed its ledger (and released its cache
+                # refs) and reset the seq for recompute; mirror on the real
+                # pools (tokens are kept - the re-prefill recomputes prompt +
+                # emitted prefix)
+                self._shared_tok.pop(victim.sid, None)
+                self.pool.free(victim.sid)
+                if self.draft_pool is not None:
+                    self.draft_pool.free(victim.sid)
+            k = self.spec.num_draft_tokens
+            hs = hybrid_step_charges(
+                self.kind, self.cfg, self.draft_cfg, self.new_chip, self.old_chip,
+                plan.chunk_specs(), plan.decode_ctxs(), k, self.interconnect)
+            for chip_name, cost, rel_s in hs.charges:
+                self._charge(CHIP_DB[chip_name], cost, at_s=self.clock + rel_s)
+            t_end = self.clock + self._dilate(self.clock, hs.duration_s)
+            if sched.cache is not None:
+                sched.cache.now_s = t_end             # publish at step-end time
+            self._count_plan(plan)
         for ch in plan.chunks:
             seq = ch.seq
             r: EngineRequest = seq.payload
-            prefix = self._prefix_tokens(r, ch.ctx_before + ch.tokens)
-            if sched.cache is not None and not self.pool.has(seq.sid):
-                self._adopt_shared(sched.cache, seq)
-            fresh = not self.pool.has(seq.sid)
-            shared = self._shared_tok.get(seq.sid, 0)
-            logits = self._chunk_prefill(self.params, self.cfg, self.pool,
-                                         seq.sid, prefix, fresh,
-                                         shared_tok=shared)
-            if self.kind in ("spec", "dsd"):
-                self._chunk_prefill(self.draft_params, self.draft_cfg,
-                                    self.draft_pool, seq.sid, prefix,
-                                    fresh, shared_tok=shared)
-            if sched.complete_chunk(seq, ch.tokens):
+            with jax.profiler.TraceAnnotation("engine.prefill"):
+                prefix = self._prefix_tokens(r, ch.ctx_before + ch.tokens)
+                if sched.cache is not None and not self.pool.has(seq.sid):
+                    self._adopt_shared(sched.cache, seq)
+                fresh = not self.pool.has(seq.sid)
+                shared = self._shared_tok.get(seq.sid, 0)
+                logits = self._chunk_prefill(self.params, self.cfg, self.pool,
+                                             seq.sid, prefix, fresh,
+                                             shared_tok=shared)
+                if self.kind in ("spec", "dsd"):
+                    self._chunk_prefill(self.draft_params, self.draft_cfg,
+                                        self.draft_pool, seq.sid, prefix,
+                                        fresh, shared_tok=shared)
+                prefilled = sched.complete_chunk(seq, ch.tokens)
+            if prefilled:
                 if seq.emitted == 0:
-                    tok = int(np.asarray(self._sample(logits))[0])
-                    r.out_tokens.append(tok)
-                    r.ttft_s = t_end - r.arrival_s
-                    r.first_token_s = r.last_token_s = t_end
-                    if sched.note_first_token(seq):
-                        self._retire_continuous(seq)
-                        continue
+                    with jax.profiler.TraceAnnotation("engine.sample"):
+                        tok = int(np.asarray(self._sample(logits))[0])
+                    with jax.profiler.TraceAnnotation("engine.emit"):
+                        r.out_tokens.append(tok)
+                        r.ttft_s = t_end - r.arrival_s
+                        r.first_token_s = r.last_token_s = t_end
+                        if sched.note_first_token(seq):
+                            self._retire_continuous(seq)
+                            continue
                 self.last_token[seq.sid] = r.out_tokens[-1]
         if plan.decodes:
             if self.kind in ("spec", "dsd"):
@@ -814,17 +845,33 @@ class ServingEngine:
     def _continuous_decode(self, decodes: "list[SchedSeq]",
                            t_end: float) -> None:
         sched = self._sched
-        sids = [s.sid for s in decodes]
-        tokens = jnp.asarray([self.last_token[s] for s in sids], jnp.int32)
-        logits = self._decode_logits(self.pool, sids, tokens)
-        new = np.asarray(self._sample(logits))
-        for seq, tok in zip(decodes, new):
-            r: EngineRequest = seq.payload
-            r.out_tokens.append(int(tok))
-            r.last_token_s = t_end
-            self.last_token[seq.sid] = int(tok)
-            if sched.note_decode(seq, 1):
-                self._retire_continuous(seq)
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            sids = [s.sid for s in decodes]
+            tokens = jnp.asarray([self.last_token[s] for s in sids], jnp.int32)
+            logits = self._decode_logits(self.pool, sids, tokens)
+        with jax.profiler.TraceAnnotation("engine.sample"):
+            new = np.asarray(self._sample(logits))
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for seq, tok in zip(decodes, new):
+                r: EngineRequest = seq.payload
+                r.out_tokens.append(int(tok))
+                r.last_token_s = t_end
+                self.last_token[seq.sid] = int(tok)
+                if sched.note_decode(seq, 1):
+                    self._retire_continuous(seq)
+
+    def _count_plan(self, plan) -> None:
+        """Fold one plan into `counters`: its preemptions, and the queue
+        wait of each request it schedules for the first time (a re-prefill
+        after preemption is not counted again)."""
+        self.counters.preempted += len(plan.preempted)
+        now = time.perf_counter()
+        for ch in plan.chunks:
+            r: EngineRequest = ch.seq.payload
+            if r.queue_wait_s is None:
+                r.queue_wait_s = now - r.submit_wall_s
+                self.counters.queued += 1
+                self.counters.queue_wait_s += r.queue_wait_s
 
     def _continuous_spec_round(self, decodes: "list[SchedSeq]",
                                t_end: float) -> None:
