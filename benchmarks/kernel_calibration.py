@@ -254,10 +254,10 @@ def validate_kernels_interpret() -> None:
     tables = jnp.asarray([[0, 1, 9], [2, 3, 4]], jnp.int32)
     lengths = jnp.asarray([11, 20], jnp.int32)
     q, kn, vn = r(B, 1, H, D), r(B, 1, KV, D), r(B, 1, KV, D)
-    a = ops.paged_decode_attention(q, kp, vp, tables, lengths, kn, vn,
-                                   max_len=21, impl="jnp")
-    b = ops.paged_decode_attention(q, kp, vp, tables, lengths, kn, vn,
-                                   max_len=21, impl="pallas")
+    a = ops.paged_decode_attention(q, kp[None], vp[None], 0, tables,
+                                   lengths, kn, vn, max_len=21, impl="jnp")
+    b = ops.paged_decode_attention(q, kp[None], vp[None], 0, tables,
+                                   lengths, kn, vn, max_len=21, impl="pallas")
     err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
     assert err < 2e-2, f"paged decode interpret mismatch: {err}"
     qc, ks, vs = r(1, 5, H, D), r(1, 5, KV, D), r(1, 5, KV, D)

@@ -112,29 +112,35 @@ def decode_attention(q, k_cache, v_cache, pos, block_k: "int | None" = None):
 # ---------------------------------------------------------------------------
 # paged attention (PagedKVPool-native)
 # ---------------------------------------------------------------------------
-def paged_decode_attention(q, k_pages, v_pages, tables, lengths, k_new, v_new,
-                           max_len: int, impl: str = "auto"):
+def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, k_new,
+                           v_new, max_len: int, impl: str = "auto"):
     """One decode step straight off the paged pool (gather-free).
 
-    q: (B, 1, H, D); k_pages/v_pages: (NBp, KV, bs, D) - ONE layer of
-    `PagedKVPool.k/v`; tables: (B, NB) int32 dump-padded block tables;
-    lengths: (B,) cached tokens per sequence; k_new/v_new: (B, 1, KV, D)
-    the step's own K/V (post-RoPE, not yet in the pool); max_len: static
-    batch-max sequence length INCLUDING the new token -> (B, 1, H, D)."""
+    q: (B, 1, H, D); k_pages/v_pages: (L, NBp, KV, bs, D) - the whole of
+    `PagedKVPool.k/v`; layer: () int32, the pool layer to read; tables:
+    (B, NB) int32 dump-padded block tables; lengths: (B,) cached tokens per
+    sequence; k_new/v_new: (B, 1, KV, D) the step's own K/V (post-RoPE, not
+    yet in the pool); max_len: static batch-max sequence length INCLUDING
+    the new token -> (B, 1, H, D)."""
     b, _, h, d = q.shape
-    kvh, bs = k_pages.shape[1], k_pages.shape[2]
+    kvh, bs = k_pages.shape[2], k_pages.shape[3]
     g = h // kvh
     impl = resolve_impl(impl)
     if impl == "jnp":
-        return _paged_decode_jnp(q, k_pages, v_pages, tables, lengths,
-                                 k_new, v_new, max_len)
-    # VMEM guard: the whole query group sits next to one streamed page
-    vmem.paged_decode_vmem(g, bs, d).assert_fits("paged_decode_attention")
+        return _paged_decode_jnp(q, k_pages[layer], v_pages[layer], tables,
+                                 lengths, k_new, v_new, max_len)
+    # page groups from the shapes; VMEM guard: the query group sits next to
+    # two slots of page groups
+    nb = tables.shape[1]
+    pages = vmem.paged_decode_pages(kvh, g, bs, d, nb)
+    compute_pages = vmem.paged_decode_compute_pages(bs, nb)
+    vmem.paged_decode_vmem(kvh, g, bs, d, pages, compute_pages).assert_fits(
+        "paged_decode_attention")
     qg = q[:, 0].reshape(b, kvh, g, d)
     out = paged_decode_attention_grouped(
-        qg, k_pages, v_pages, tables, lengths.astype(jnp.int32),
+        qg, k_pages, v_pages, layer, tables, lengths.astype(jnp.int32),
         k_new.transpose(0, 2, 1, 3), v_new.transpose(0, 2, 1, 3),
-        interpret=_interpret(),
+        pages=pages, compute_pages=compute_pages, interpret=_interpret(),
     )
     return out.reshape(b, 1, h, d)
 

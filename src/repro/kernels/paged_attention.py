@@ -1,32 +1,36 @@
 """Pallas TPU paged attention: gather-free decode + fused chunked prefill.
 
-Both kernels consume the serving layer's `PagedKVPool` storage DIRECTLY -
-one layer's (num_blocks + 1, KV, block_size, D) page arrays plus int32
-block tables - instead of a densified contiguous cache. The block-table
-indirection rides on `PrefetchScalarGridSpec`: tables arrive as
-scalar-prefetch arguments, and the K/V page BlockSpec *index maps* read
-them, so Mosaic streams exactly the physical pages each sequence owns
-HBM->VMEM and the O(B*S*L) gather/scatter round-trip of the dense engine
-path disappears.
+Both kernels consume the serving layer's `PagedKVPool` storage DIRECTLY,
+through int32 block tables, instead of a densified contiguous cache, so
+the O(B*S*L) gather/scatter round-trip of the dense engine path
+disappears.
 
 paged_decode_attention_grouped
-    One query token per sequence attends over its paged prefix. Grid
-    (B, KV, NB): each program owns one (batch, kv-head) pair and walks the
-    sequence's pages with online-softmax state for all G = H/KV grouped
-    query heads in VMEM scratch (the GQA reuse win, as in
-    decode_attention.py). The new token's K/V is NOT yet in the pool -
-    it is passed separately and merged into the running softmax in the
-    finalize step, so the pool write-back shrinks to one slot per layer
-    (`PagedKVPool.scatter_append`). Table rows are dump-padded; pages at
-    or past the ragged tail are skipped (`i * bs >= len`) and the tail
-    page's overhang is masked (`kpos < len`).
+    One query token per sequence attends over its paged prefix. The K/V
+    operands are the pool's whole (L, NBp, KV, bs, D) arrays, left in HBM;
+    the layer index, lengths and tables are scalar-prefetched. Grid (B,),
+    run in order: each program owns one sequence and all its kv heads, and
+    copies the pages its length covers by manual DMA, P pages a group
+    (one DMA per page and side moves every kv head), double-buffered in
+    two VMEM slots: group g + 1 - or, in the last group, the next
+    sequence's group 0 - is in flight while group g is scored. P comes
+    from the shapes (`vmem.paged_decode_pages`). Groups are scored
+    `compute_pages` pages at a time with fp32 online-softmax state per kv
+    head, for all G = H/KV grouped query heads (the GQA reuse win, as in
+    decode_attention.py); scores and values at or past the length are
+    masked (`kpos < len`), so stale slot rows are never observed and
+    dump-padded table entries are never read. The new token's K/V is
+    NOT yet in the pool - it is passed separately and merged into the
+    running softmax last, so the pool write-back shrinks to one slot per
+    layer (`PagedKVPool.scatter_append`).
 
 paged_prefill_attention_fused
     One prefill chunk (C tokens of a single sequence) attends over the
     sequence's prior paged context AND itself causally - the hybrid
     chunked-prefill step of the continuous scheduler. Grid (KV, NB + 1):
-    the first NB steps stream context pages (fully visible to every chunk
-    row, ragged tail masked); the final step merges the chunk's own K/V
+    the first NB steps stream context pages, one a step through a
+    BlockSpec index map that reads the scalar-prefetched table (fully
+    visible to every chunk row, ragged tail masked); the final step merges the chunk's own K/V
     with the causal intra-chunk mask and normalizes. Query rows are laid
     out token-major per kv head ((C*G, D), row r is token r // G), so one
     score matrix covers the whole grouped-query chunk.
@@ -47,117 +51,192 @@ NEG_INF = -1e30
 # decode
 # ---------------------------------------------------------------------------
 def _paged_decode_kernel(
+    layer_ref,                    # scalar-prefetch: (1,) pool layer
     len_ref,                      # scalar-prefetch: (B,) cached lengths
     tbl_ref,                      # scalar-prefetch: (B, NB) block tables
-    q_ref,                        # (1, 1, G, D)
-    kn_ref, vn_ref,               # (1, 1, 1, D) - the step's new K/V
-    k_ref, v_ref,                 # (1, 1, bs, D) - one physical page
-    o_ref,                        # (1, 1, G, D)
-    m_scr, l_scr, acc_scr,        # (G, 1), (G, 1), (G, D)
+    q_ref,                        # (1, KV, G, D)
+    kn_ref, vn_ref,               # (1, KV, 1, D) - the step's new K/V
+    k_hbm, v_hbm,                 # (L, NBp, KV, bs, D) - the whole pool, in HBM
+    o_ref,                        # (1, KV, G, D)
+    k_buf, v_buf,                 # (2, KV, P, bs, D) - two slots of P pages
+    sems,                         # DMA semaphores (2, 2): [K/V, slot]
+    slot_ref,                     # SMEM (1,): slot of this sequence's group 0
+    m_scr, l_scr, acc_scr,        # (KV, G, 1), (KV, G, 1), (KV, G, D)
     *,
     block_size: int,
+    pages: int,
+    compute_pages: int,
     sm_scale: float,
-    nb: int,
 ):
-    bi = pl.program_id(0)
-    ik = pl.program_id(2)
+    b = pl.program_id(0)
+    has_next = b + 1 < pl.num_programs(0)
+    kvh = q_ref.shape[1]
+    d = q_ref.shape[3]
+    layer = layer_ref[0]
+    span = compute_pages * block_size
 
-    @pl.when(ik == 0)
-    def init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def held_pages(bi, g):
+        """Pool pages of sequence bi's group g: P, or fewer in its last."""
+        return jnp.minimum(pages, pl.cdiv(len_ref[bi], block_size) - g * pages)
 
-    cur = len_ref[bi]
-    # skip pages entirely past this sequence's cached prefix (dump-padded
-    # table rows land here: their pages are fetched but never read)
-    @pl.when(ik * block_size < cur)
-    def compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)           # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale                                   # (G, bs)
-        kpos = ik * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < cur, s, NEG_INF)          # ragged tail mask
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
+    def page_copies(bi, g, slot, i):
+        page = tbl_ref[bi, g * pages + i]
+        return [pltpu.make_async_copy(src.at[layer, page], dst.at[slot, :, i],
+                                      sems.at[j, slot])
+                for j, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
 
-    @pl.when(ik == nb - 1)
-    def finalize():
-        # merge the current token's self-attention term (its K/V is not in
-        # the pool yet - scatter_append writes it after the step)
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        kn = kn_ref[0, 0].astype(jnp.float32)          # (1, D)
-        vn = vn_ref[0, 0].astype(jnp.float32)
+    def start(bi, g, slot):
+        def one(i, c):
+            for cp in page_copies(bi, g, slot, i):
+                cp.start()
+            return c
+        jax.lax.fori_loop(0, held_pages(bi, g), one, 0)
+
+    def wait(bi, g, slot):
+        def one(i, c):
+            for cp in page_copies(bi, g, slot, i):
+                cp.wait()
+            return c
+        jax.lax.fori_loop(0, held_pages(bi, g), one, 0)
+
+    # the first program fetches its own group 0; every program fetches the
+    # next sequence's group 0 while it computes its own last group
+    @pl.when(b == 0)
+    def first():
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    cur = len_ref[b]
+    slot0 = slot_ref[0]
+    n_groups = pl.cdiv(cur, pages * block_size)
+
+    def group(g, c):
+        slot = (slot0 + g) % 2
+
+        @pl.when(g + 1 < n_groups)
+        def prefetch_own():
+            start(b, g + 1, 1 - slot)
+
+        @pl.when((g + 1 == n_groups) & has_next)
+        def prefetch_next():
+            start(b + 1, 0, 1 - slot)
+
+        wait(b, g, slot)
+
+        def block(j, c2):
+            page0 = j * compute_pages
+            kpos0 = (g * pages + page0) * block_size
+            for h in range(kvh):
+                q = q_ref[0, h]                                  # (G, D)
+                k = k_buf[slot, h, pl.ds(page0, compute_pages)].reshape(span, d)
+                v = v_buf[slot, h, pl.ds(page0, compute_pages)].reshape(span, d)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale  # (G, span)
+                kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(kpos < cur, s, NEG_INF)            # ragged tail mask
+                vpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, (span, d), 0)
+                v = jnp.where(vpos < cur, v.astype(jnp.float32), 0.0)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_scr[h] = m_new
+            return c2
+
+        jax.lax.fori_loop(0, pl.cdiv(held_pages(b, g), compute_pages), block, 0)
+        return c
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+
+    @pl.when((n_groups == 0) & has_next)
+    def prefetch_next_empty():
+        start(b + 1, 0, slot0)
+
+    slot_ref[0] = (slot0 + n_groups) % 2
+
+    # merge the current token's self-attention term (its K/V is not in the
+    # pool yet - scatter_append writes it after the step)
+    for h in range(kvh):
+        q = q_ref[0, h].astype(jnp.float32)                     # (G, D)
+        kn = kn_ref[0, h].astype(jnp.float32)                   # (1, D)
+        vn = vn_ref[0, h].astype(jnp.float32)
         s_self = jax.lax.dot_general(
             q, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale                                    # (G, 1)
-        m_prev = m_scr[...]
+        ) * sm_scale                                             # (G, 1)
+        m_prev = m_scr[h]
         m_new = jnp.maximum(m_prev, s_self)
         p = jnp.exp(s_self - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l = l_scr[...] * alpha + p
-        acc = acc_scr[...] * alpha + p * vn
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        l = l_scr[h] * alpha + p
+        acc = acc_scr[h] * alpha + p * vn
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit,
+                   static_argnames=("pages", "compute_pages", "interpret"))
 def paged_decode_attention_grouped(
     q: jax.Array,        # (B, KV, G, D) - query heads grouped by kv head
-    k_pages: jax.Array,  # (NBp, KV, bs, D) - ONE layer of the pool storage
+    k_pages: jax.Array,  # (L, NBp, KV, bs, D) - the whole pool storage
     v_pages: jax.Array,
+    layer: jax.Array,    # () int32 - the layer of the pool to read
     tables: jax.Array,   # (B, NB) int32 physical page ids (dump-padded)
     lengths: jax.Array,  # (B,) int32 cached tokens (new token sits at this index)
     k_new: jax.Array,    # (B, KV, 1, D) - this step's K/V (post-RoPE)
     v_new: jax.Array,
+    *,
+    pages: int,          # pages per DMA group (vmem.paged_decode_pages)
+    compute_pages: int,  # pages scored at once; divides `pages`
     interpret: bool = False,
 ) -> jax.Array:
     b, kvh, g, d = q.shape
-    block_size = k_pages.shape[2]
-    nb = tables.shape[1]
-    assert nb >= 1, "tables must cover at least one page"
+    block_size = k_pages.shape[3]
+    assert tables.shape[1] >= 1, "tables must cover at least one page"
+    assert pages % compute_pages == 0, (pages, compute_pages)
 
     kernel = functools.partial(
-        _paged_decode_kernel, block_size=block_size, sm_scale=d ** -0.5, nb=nb
-    )
+        _paged_decode_kernel, block_size=block_size, pages=pages,
+        compute_pages=compute_pages, sm_scale=d ** -0.5)
+    seq = lambda bi, layer_r, lens, tbl: (bi, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kvh, nb),
+        num_scalar_prefetch=3,
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ik, lens, tbl: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, d), lambda bi, hi, ik, lens, tbl: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, d), lambda bi, hi, ik, lens, tbl: (bi, hi, 0, 0)),
-            # the paged-attention trick: the page index map READS the
-            # prefetched block table, so each grid step streams exactly
-            # the physical page tbl[bi, ik] for this sequence
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bi, hi, ik, lens, tbl: (tbl[bi, ik], hi, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bi, hi, ik, lens, tbl: (tbl[bi, ik], hi, 0, 0)),
+            pl.BlockSpec((1, kvh, g, d), seq),
+            pl.BlockSpec((1, kvh, 1, d), seq),
+            pl.BlockSpec((1, kvh, 1, d), seq),
+            # the pool stays in HBM: the kernel DMAs the pages each
+            # sequence's table names, P at a time
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bi, hi, ik, lens, tbl: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, d), seq),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((2, kvh, pages, block_size, d), k_pages.dtype),
+            pltpu.VMEM((2, kvh, pages, block_size, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
+        # a program prefetches the next sequence's pages: the grid runs in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
-      q, k_new, v_new, k_pages, v_pages)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths.astype(jnp.int32),
+      tables.astype(jnp.int32), q, k_new, v_new, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

@@ -57,17 +57,45 @@ def decode_attention_vmem(group: int, block_k: int, head_dim: int,
     return VmemEstimate(q + kv + out + scores, scratch)
 
 
-def paged_decode_vmem(group: int, block_size: int, head_dim: int,
+# tokens of one sequence the paged decode kernel scores in one pass: its
+# time follows the number of these blocks more than the DMA group size
+DECODE_COMPUTE_TOKENS = 512
+
+
+def paged_decode_compute_pages(block_size: int, table_width: int) -> int:
+    """Pages the paged decode kernel scores at once, never above the table."""
+    return max(1, min(DECODE_COMPUTE_TOKENS // block_size, table_width))
+
+
+def paged_decode_vmem(num_kv_heads: int, group: int, block_size: int,
+                      head_dim: int, pages: int, compute_pages: int,
                       dtype_bytes: int = 2) -> VmemEstimate:
-    """Paged decode: per-program working set is one (batch, kv-head) pair's
-    G query rows + one streamed physical page + the step's new K/V."""
-    q = group * head_dim * dtype_bytes
-    kv = 2 * STREAM_COPIES * block_size * head_dim * dtype_bytes
-    new = 2 * head_dim * dtype_bytes
-    out = group * head_dim * dtype_bytes
-    scratch = (2 * group + group * head_dim) * 4              # m, l, acc fp32
-    scores = group * block_size * 4
-    return VmemEstimate(q + kv + new + out + scores, scratch)
+    """Paged decode: one sequence's program holds its kv heads' G query
+    rows, the step's new K/V and its output (each pipelined), two slots of
+    `pages` pool pages of K and of V (its manual DMA groups), and one
+    head's fp32 V, scores and probabilities over `compute_pages` pages."""
+    rows = num_kv_heads * group * head_dim * dtype_bytes     # q block, out block
+    new = 2 * num_kv_heads * head_dim * dtype_bytes          # k_new, v_new blocks
+    tiles = STREAM_COPIES * (2 * rows + new)
+    page = num_kv_heads * block_size * head_dim * dtype_bytes
+    groups = 2 * STREAM_COPIES * pages * page                # K and V, two slots
+    span = compute_pages * block_size
+    work = span * head_dim * 4 + 2 * group * span * 4
+    scratch = num_kv_heads * (2 * group + group * head_dim) * 4   # m, l, acc fp32
+    return VmemEstimate(tiles + groups + work, scratch)
+
+
+def paged_decode_pages(num_kv_heads: int, group: int, block_size: int,
+                       head_dim: int, table_width: int,
+                       dtype_bytes: int = 2) -> int:
+    """Pages per DMA group of the paged decode kernel: enough whole compute
+    blocks to cover the table, or the most whose working set still fits."""
+    c = paged_decode_compute_pages(block_size, table_width)
+    n = -(-table_width // c)
+    while n > 1 and not paged_decode_vmem(num_kv_heads, group, block_size, head_dim,
+                                          n * c, c, dtype_bytes).fits:
+        n -= 1
+    return n * c
 
 
 def paged_prefill_vmem(rows: int, chunk: int, block_size: int, head_dim: int,

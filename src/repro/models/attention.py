@@ -254,8 +254,9 @@ def attention_decode_block(
 def attention_paged_decode_block(
     p: dict,
     x: jax.Array,             # (B, 1, D_model)
-    k_pages: jax.Array,       # (NBp, KV, bs, D) - one pool layer
+    k_pages: jax.Array,       # (L, NBp, KV, bs, D) - the whole pool
     v_pages: jax.Array,
+    layer: jax.Array,         # () int32 - this layer's index into the pool
     tables: jax.Array,        # (B, NB) int32 dump-padded block tables
     lengths: jax.Array,       # (B,) cached tokens (new token's position)
     positions_rope: jax.Array,  # (B, 1)
@@ -268,10 +269,11 @@ def attention_paged_decode_block(
 
     The dense path (`attention_decode_block`) needs the engine to gather
     each sequence's pages into a contiguous (B, KV, S_max, D) cache first;
-    this variant hands the pool's page array + block tables straight to
-    `kops.paged_decode_attention`, and returns the step's own (k, v) for
-    the caller to `scatter_append` into the pool. m-RoPE is unsupported
-    (the engine gates VLM families to the gather path)."""
+    this variant hands the pool's whole page arrays, the layer index and
+    the block tables straight to `kops.paged_decode_attention`, and
+    returns the step's own (k, v) for the caller to `scatter_append` into
+    the pool. m-RoPE is unsupported (the engine gates VLM families to the
+    gather path)."""
     a = cfg.attn
     assert a.m_rope_sections is None, "paged decode does not support m-rope"
     b = x.shape[0]
@@ -284,7 +286,8 @@ def attention_paged_decode_block(
     from repro.kernels import ops as kops
 
     o = kops.paged_decode_attention(
-        q, k_pages, v_pages, tables, lengths, k, v, max_len=max_len, impl=impl)
+        q, k_pages, v_pages, layer, tables, lengths, k, v, max_len=max_len,
+        impl=impl)
     return o.reshape(b, 1, -1) @ p["wo"], k, v
 
 

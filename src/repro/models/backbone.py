@@ -494,11 +494,14 @@ def _serve_step_paged(params, pages_k, pages_v, tables, lengths, tokens,
     x = embed_tokens(params["tok"], tokens)[:, None, :]            # (B, 1, D)
     prope = lengths[:, None].astype(jnp.int32)                     # (B, 1)
 
+    # the pool is closed over whole and each layer reads it at its index:
+    # no per-layer slice of the pool is cut out and copied
     def body(xc, inp):
-        lp, kp, vp = inp
+        lp, layer = inp
         h, kt, vt = attention_paged_decode_block(
-            lp["attn"], rmsnorm(lp["norm1"], xc, cfg.norm_eps), kp, vp,
-            tables, lengths, prope, cfg, exec_cfg, max_len=max_len, impl=impl)
+            lp["attn"], rmsnorm(lp["norm1"], xc, cfg.norm_eps), pages_k,
+            pages_v, layer, tables, lengths, prope, cfg, exec_cfg,
+            max_len=max_len, impl=impl)
         xc = xc + h
         xn = rmsnorm(lp["norm2"], xc, cfg.norm_eps)
         if cfg.family == "moe":
@@ -507,7 +510,8 @@ def _serve_step_paged(params, pages_k, pages_v, tables, lengths, tokens,
             xc = xc + swiglu(lp["ffn"], xn)
         return xc, (kt, vt)
 
-    x, (kt, vt) = jax.lax.scan(body, x, (params["layers"], pages_k, pages_v))
+    layers = jnp.arange(pages_k.shape[0], dtype=jnp.int32)
+    x, (kt, vt) = jax.lax.scan(body, x, (params["layers"], layers))
     xn = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = lm_logits(params["tok"], xn, cfg)[:, 0]
     return logits, kt[:, :, 0], vt[:, :, 0]                        # (L, B, KV, D)

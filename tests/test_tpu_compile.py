@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops as kops
+from repro.kernels import vmem
 from repro.kernels.decode_attention import decode_attention_grouped
 from repro.kernels.flash_attention import flash_attention_hsd
 from repro.kernels.paged_attention import (
@@ -61,17 +62,24 @@ def _compile(fn, *args, **static):
     return lowered.compile()
 
 
-def test_paged_decode_compiles(one_chip):
-    b, nb = 8, 64
+# (layers, pool pages with the dump page, kv heads, group, batch) of the
+# served pools: Yi-6B whole, and an 8-layer Yi-34B stage (GQA group 7)
+@pytest.mark.parametrize("layers,pool_pages,kvh,g,b", [
+    (32, 2900 + 1, KV, G, 57), (8, 7400 + 1, 8, 7, 60)])
+def test_paged_decode_compiles(one_chip, layers, pool_pages, kvh, g, b):
+    nb = 4096 // BLOCK                         # the longest table
     bf = jnp.bfloat16
+    pages = vmem.paged_decode_pages(kvh, g, BLOCK, D, nb)
     _compile(paged_decode_attention_grouped,
-             _sds((b, KV, G, D), bf, one_chip),
-             _sds((POOL_PAGES, KV, BLOCK, D), bf, one_chip),
-             _sds((POOL_PAGES, KV, BLOCK, D), bf, one_chip),
+             _sds((b, kvh, g, D), bf, one_chip),
+             _sds((layers, pool_pages, kvh, BLOCK, D), bf, one_chip),
+             _sds((layers, pool_pages, kvh, BLOCK, D), bf, one_chip),
+             _sds((), jnp.int32, one_chip),
              _sds((b, nb), jnp.int32, one_chip),
              _sds((b,), jnp.int32, one_chip),
-             _sds((b, KV, 1, D), bf, one_chip),
-             _sds((b, KV, 1, D), bf, one_chip),
+             _sds((b, kvh, 1, D), bf, one_chip),
+             _sds((b, kvh, 1, D), bf, one_chip),
+             pages=pages, compute_pages=vmem.paged_decode_compute_pages(BLOCK, nb),
              interpret=False)
 
 
